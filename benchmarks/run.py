@@ -19,6 +19,8 @@ SUITES = ("table1", "table2", "table3", "table4", "table5", "table6",
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     want = sys.argv[1:] or list(SUITES)
     print("name,us_per_call,derived")
     failures = 0

@@ -16,6 +16,8 @@ from benchmarks.table3_latency_speedup import static_opt
 
 
 def main():
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     for regime in ("llama", "gemma"):
         print(f"== {regime} pair "
               f"({'strong draft' if regime == 'llama' else 'weak, divergent draft'}) ==")

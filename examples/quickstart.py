@@ -20,6 +20,8 @@ from repro.serving.request import Request
 
 
 def main():
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     # 1. a reduced SmolLM-family target + a correlated draft
     cfg = get_config("smollm-135m").reduced()
     params_t = init_params(model_specs(cfg), jax.random.PRNGKey(1),

@@ -31,6 +31,8 @@ def build_pair(smoke: bool):
 
 
 def main():
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.core.drafters import available_drafters
 
     ap = argparse.ArgumentParser()

@@ -46,6 +46,8 @@ def _engine(cfg_t, cfg_d, pt, pd):
 
 
 def main():
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.serving.frontend import ServingFrontend
 
     ap = argparse.ArgumentParser()

@@ -21,6 +21,8 @@ from repro.training.train import train_loop
 
 
 def main():
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     cfg_t, _, pt, _, _ = common.build_pair("llama")   # cached target
     cfg_d = common.draft_config()
     stream = common.mixed_stream()

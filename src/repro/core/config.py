@@ -311,6 +311,13 @@ class ServingConfig:
     # never starved or dropped; 0 disables deferral entirely (predicted
     # violations are still surfaced).
     slo_defer_limit: int = 4
+    # precision of the engine's f32 matmuls (prefill and round programs;
+    # ``jax.default_matmul_precision``).  At a TPU's default precision an
+    # f32 dot is one bf16 pass, and a one-row decode and a K+1-row verify
+    # then differ by a sizeable share of the logit spread: greedy
+    # speculative streams left the autoregressive one well away from
+    # ties on a v5e (PERF.md).  "highest" keeps them equal.
+    matmul_precision: str = "highest"
 
     def blocks_per_seq(self) -> int:
         """Block-table width: worst-case blocks one sequence can hold."""

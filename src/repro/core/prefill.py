@@ -17,6 +17,7 @@ slots with one fused scatter per leaf.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Tuple
 
@@ -24,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.config import ModelConfig
+from repro.kernels import ops as kernel_ops
 from repro.models import cache as cache_lib
 from repro.models.transformer import forward
 
@@ -159,8 +161,13 @@ def prefill_paged_tail(params: PyTree, cfg: ModelConfig, pool_k: jax.Array,
                                          k_scale=k_scale, v_scale=v_scale)
     t = tokens.shape[1]
     write_mask = jnp.arange(t)[None] < tail_lens[:, None]
-    logits, cache, _ = forward(params, cfg, tokens, cache=cache,
-                               mode="decode", write_mask=write_mask)
+    # a decode-mode forward reaches the paged Pallas kernel on a TPU,
+    # which runs per shard of the plan's mesh (kernels/ops.py)
+    sharded = (contextlib.nullcontext() if plan is None else
+               kernel_ops.sharded_kernels(plan))
+    with sharded:
+        logits, cache, _ = forward(params, cfg, tokens, cache=cache,
+                                   mode="decode", write_mask=write_mask)
     cache["length"] = (start_lens + tail_lens).astype(jnp.int32)
     rows = jnp.arange(tokens.shape[0])
     last = logits[rows, jnp.maximum(tail_lens - 1, 0)]

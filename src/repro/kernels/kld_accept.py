@@ -22,7 +22,8 @@ Online accumulation (flash-softmax style, per (b, t) row):
     H_q   = lse_q - a_qq / s_q
     p_tok = e^{tl_tok - lse_p} ;  q_tok = e^{dl_tok - lse_q}
 
-Grid: (B*T, V // BV) — vocab blocks innermost, state in SMEM/VMEM scratch.
+Grid: (B*T, V // BV) — vocab blocks innermost, the row's token id in
+scalar prefetch, the running state in VMEM scratch as [1, 1] tiles.
 """
 from __future__ import annotations
 
@@ -44,44 +45,43 @@ def _kernel(tok_ref, tl_ref, dl_ref,
 
     @pl.when(vb == 0)
     def _init():
-        state_ref[0] = NEG_INF   # m_p
-        state_ref[1] = 0.0       # s_p
-        state_ref[2] = 0.0       # a_pd
-        state_ref[3] = NEG_INF   # m_q
-        state_ref[4] = 0.0       # s_q
-        state_ref[5] = 0.0       # a_qq
-        state_ref[6] = NEG_INF   # tl[token]
-        state_ref[7] = NEG_INF   # dl[token]
+        for i, v0 in enumerate((NEG_INF, 0.0, 0.0, NEG_INF, 0.0, 0.0,
+                                NEG_INF, NEG_INF)):
+            state_ref[i] = jnp.full((1, 1), v0, jnp.float32)
 
-    tl = tl_ref[0].astype(jnp.float32)          # [BV]
-    dl = dl_ref[0].astype(jnp.float32)          # [BV]
-    tok = tok_ref[0]
+    tl = tl_ref[0].astype(jnp.float32)          # [1, BV]
+    dl = dl_ref[0].astype(jnp.float32)          # [1, BV]
+    tok = tok_ref[pl.program_id(0)]
+
+    def rsum(x):
+        return jnp.sum(x, axis=-1, keepdims=True)
 
     # --- target-side online stats -----------------------------------------
     m_p, s_p, a_pd = state_ref[0], state_ref[1], state_ref[2]
-    m_new = jnp.maximum(m_p, jnp.max(tl))
+    m_new = jnp.maximum(m_p, jnp.max(tl, axis=-1, keepdims=True))
     alpha = jnp.exp(m_p - m_new)
     e_p = jnp.exp(tl - m_new)
     state_ref[0] = m_new
-    state_ref[1] = s_p * alpha + e_p.sum()
-    state_ref[2] = a_pd * alpha + (e_p * (tl - dl)).sum()
+    state_ref[1] = s_p * alpha + rsum(e_p)
+    state_ref[2] = a_pd * alpha + rsum(e_p * (tl - dl))
 
     # --- draft-side online stats -------------------------------------------
     m_q, s_q, a_qq = state_ref[3], state_ref[4], state_ref[5]
-    mq_new = jnp.maximum(m_q, jnp.max(dl))
+    mq_new = jnp.maximum(m_q, jnp.max(dl, axis=-1, keepdims=True))
     beta = jnp.exp(m_q - mq_new)
     e_q = jnp.exp(dl - mq_new)
     state_ref[3] = mq_new
-    state_ref[4] = s_q * beta + e_q.sum()
-    state_ref[5] = a_qq * beta + (e_q * dl).sum()
+    state_ref[4] = s_q * beta + rsum(e_q)
+    state_ref[5] = a_qq * beta + rsum(e_q * dl)
 
-    # --- token pick-up -------------------------------------------------------
+    # --- token pick-up: a masked reduction over the block that holds it ----
     lo = vb * bv
-    idx = tok - lo
-    in_block = (idx >= 0) & (idx < bv)
-    idx_c = jnp.clip(idx, 0, bv - 1)
-    state_ref[6] = jnp.where(in_block, tl[idx_c], state_ref[6])
-    state_ref[7] = jnp.where(in_block, dl[idx_c], state_ref[7])
+
+    @pl.when((tok >= lo) & (tok < lo + bv))
+    def _pick():
+        hit = jax.lax.broadcasted_iota(jnp.int32, tl.shape, 1) == tok - lo
+        state_ref[6] = rsum(jnp.where(hit, tl, 0.0))
+        state_ref[7] = rsum(jnp.where(hit, dl, 0.0))
 
     @pl.when(vb == nvb - 1)
     def _finalize():
@@ -104,7 +104,8 @@ def fused_kld_accept(target_logits: jax.Array, draft_logits: jax.Array,
     Returns per [B,T]: (kld, draft_entropy, p_target(tok), q_draft(tok))."""
     b, t, v = target_logits.shape
     n = b * t
-    bv = min(block_v, v)
+    # the logit tile's lane dim must be whole or a multiple of 128
+    bv = v if v <= block_v else max(block_v // 128, 1) * 128
     if v % bv:
         pad = bv - v % bv
         target_logits = jnp.pad(target_logits, ((0, 0), (0, 0), (0, pad)),
@@ -113,27 +114,27 @@ def fused_kld_accept(target_logits: jax.Array, draft_logits: jax.Array,
                                constant_values=NEG_INF)
         v += pad
     nvb = v // bv
-    tl = target_logits.reshape(n, v)
-    dl = draft_logits.reshape(n, v)
+    # a unit axis keeps every block's last two dims whole or lane-aligned
+    tl = target_logits.reshape(n, 1, v)
+    dl = draft_logits.reshape(n, 1, v)
     tok = draft_tokens.reshape(n).astype(jnp.int32)
 
-    shapes = jax.ShapeDtypeStruct((n,), jnp.float32)
-    kld, ent, ptok, qtok = pl.pallas_call(
-        functools.partial(_kernel, nvb=nvb, bv=bv),
+    row = pl.BlockSpec((1, 1, 1), lambda ni, vi, tk: (ni, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(n, nvb),
         in_specs=[
-            pl.BlockSpec((1,), lambda ni, vi: (ni,)),
-            pl.BlockSpec((1, bv), lambda ni, vi: (ni, vi)),
-            pl.BlockSpec((1, bv), lambda ni, vi: (ni, vi)),
+            pl.BlockSpec((1, 1, bv), lambda ni, vi, tk: (ni, 0, vi)),
+            pl.BlockSpec((1, 1, bv), lambda ni, vi, tk: (ni, 0, vi)),
         ],
-        out_specs=[
-            pl.BlockSpec((1,), lambda ni, vi: (ni,)),
-            pl.BlockSpec((1,), lambda ni, vi: (ni,)),
-            pl.BlockSpec((1,), lambda ni, vi: (ni,)),
-            pl.BlockSpec((1,), lambda ni, vi: (ni,)),
-        ],
+        out_specs=[row, row, row, row],
+        scratch_shapes=[pltpu.VMEM((8, 1, 1), jnp.float32)],
+    )
+    shapes = jax.ShapeDtypeStruct((n, 1, 1), jnp.float32)
+    kld, ent, ptok, qtok = pl.pallas_call(
+        functools.partial(_kernel, nvb=nvb, bv=bv),
+        grid_spec=grid_spec,
         out_shape=[shapes, shapes, shapes, shapes],
-        scratch_shapes=[pltpu.SMEM((8,), jnp.float32)],
         interpret=interpret,
     )(tok, tl, dl)
     return (kld.reshape(b, t), ent.reshape(b, t),

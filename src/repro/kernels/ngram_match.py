@@ -5,23 +5,29 @@ recent earlier occurrence of the sequence's trailing ``n``-gram inside its
 own known text and replaying the tokens that followed it — zero draft
 parameters, zero draft KV.  The hot loop is a batched windowed
 string-match over int32 token buffers ``[B, L]``; on accelerators the
-whole row fits in VMEM, so one program per sequence streams the buffer
-once and does all ``n`` shifted comparisons on-chip instead of ``n``
-separate HBM sweeps of an XLA gather pipeline.
+whole row fits in VMEM, so one program per sequence does all ``n``
+shifted comparisons and every reduction on-chip instead of an XLA gather
+pipeline.
 
 Layout / grid
 -------------
-  tokens  [B, L] int32   known text per sequence (history + pending)
-  ctx     [B, 1] int32   how many leading entries are real
-  out     [B, K] int32   proposed continuation (zero-padded)
-  cnt     [B, 1] int32   number of real proposals (0 = no match)
+  ctx     [B]        int32   how many leading entries are real (scalar
+                             prefetch: it lives in SMEM)
+  shifted [B, n, L]  int32   plane j = the known text shifted left by j,
+                             padded with -1 (never a token id); plane 0
+                             is the text itself
+  out     [B, 1, K]  int32   proposed continuation (zero-padded)
+  cnt     [B, 1, 1]  int32   number of real proposals (0 = no match)
 
   grid = (B,) — one program per sequence; ``n``/``k`` are small static
-  constants, so the shifted-equality reduction unrolls fully.  All
-  indexing is mask-and-reduce (TPU-safe: no 1-D iota, no dynamic
-  gather): the suffix values, the argmax-of-last-match, and the ``k``
-  continuation picks are each a broadcast compare + reduction over the
-  [1, L] tile.
+  constants, so the shifted-equality reduction unrolls fully.  The
+  wrapper builds the ``n`` shifted planes (a lane shift by a static
+  offset is a plain XLA slice there), so the kernel compares whole
+  ``[1, L]`` rows only.  All indexing is mask-and-reduce (no 1-D iota, no
+  dynamic gather): the suffix values, the argmax-of-last-match, and the
+  ``k`` continuation picks are each a broadcast compare + lane reduction.
+  The unit axes keep every block's last two dims whole, as the TPU
+  lowering requires.
 """
 from __future__ import annotations
 
@@ -31,35 +37,31 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(ctx_ref, tok_ref, out_ref, cnt_ref, *, n: int, k: int, l: int):
-    row = tok_ref[0, :]                                    # [L] int32
-    c = ctx_ref[0, 0]                                      # scalar int32
-    idx = jax.lax.broadcasted_iota(jnp.int32, (1, l), 1)[0]
+    c = ctx_ref[pl.program_id(0)]                          # scalar int32
+    row = tok_ref[0, 0:1, :]                               # [1, L]
+    idx = jax.lax.broadcasted_iota(jnp.int32, (1, l), 1)
 
-    match = jnp.ones((l,), bool)
-    for j in range(n):
-        # suffix value s_j = row[c - n + j] via masked reduction
-        sj = jnp.sum(jnp.where(idx == c - n + j, row, 0))
-        # row[i + j] as a static shift padded with -1 (never a token id)
-        if j:
-            shifted = jnp.concatenate(
-                [row[j:], jnp.full((j,), -1, row.dtype)])
-        else:
-            shifted = row
-        match = match & (shifted == sj)
-    # >= 1 known continuation (also kills the trivial suffix occurrence)
-    match = match & (idx + n <= c - 1) & (c >= n + 1)
+    def pick(pos):
+        # row[pos] as a masked lane reduction ([1, 1]; 0 when out of range)
+        return jnp.sum(jnp.where(idx == pos, row, 0), axis=-1, keepdims=True)
 
-    best = jnp.max(jnp.where(match, idx, -1))              # most recent
-    found = best >= 0
-    cnt = jnp.where(found, jnp.minimum(jnp.int32(k), c - (best + n)),
-                    0).astype(jnp.int32)
-    cnt_ref[0, 0] = cnt
+    match = idx + n <= c - 1   # >= 1 known continuation (also kills the
+    for j in range(n):         # trivial suffix occurrence, and c < n + 1)
+        match = match & (tok_ref[0, j:j + 1, :] == pick(c - n + j))
+
+    best = jnp.max(jnp.where(match, idx, -1), axis=-1, keepdims=True)
+    cnt = jnp.where(best >= 0, jnp.minimum(k, c - (best + n)), 0)
+    cnt_ref[0] = cnt.astype(jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    out = jnp.zeros((1, k), jnp.int32)
     for m in range(k):
-        tm = jnp.sum(jnp.where(idx == best + n + m, row, 0))
-        out_ref[0, m] = jnp.where(m < cnt, tm, 0).astype(jnp.int32)
+        tm = jnp.where(m < cnt, pick(best + n + m), 0)
+        out = jnp.where(lane == m, tm, out)
+    out_ref[0] = out
 
 
 @functools.partial(jax.jit, static_argnames=("n", "k", "interpret"))
@@ -69,26 +71,31 @@ def ngram_suffix_propose(tokens: jax.Array, ctx_len: jax.Array, *, n: int,
     """tokens [B, L] int32; ctx_len [B] int32.  Returns
     ``(proposed [B, K] int32 zero-padded, count [B] int32)`` — bit-exact
     against :func:`repro.kernels.ref.ngram_propose_ref`."""
-    assert n >= 1, "suffix length must be >= 1"
+    if n < 1:
+        raise ValueError("suffix length must be >= 1")
     b, l = tokens.shape
     if k == 0:
         return (jnp.zeros((b, 0), jnp.int32),
                 jnp.zeros((b,), jnp.int32))
+    tokens = tokens.astype(jnp.int32)
+    padded = jnp.pad(tokens, ((0, 0), (0, n - 1)), constant_values=-1)
+    shifted = jnp.stack([padded[:, j:j + l] for j in range(n)], axis=1)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, n, l), lambda bi, c: (bi, 0, 0))],
+        out_specs=[
+            pl.BlockSpec((1, 1, k), lambda bi, c: (bi, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda bi, c: (bi, 0, 0)),
+        ],
+    )
     out, cnt = pl.pallas_call(
         functools.partial(_kernel, n=n, k=k, l=l),
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bi: (bi, 0)),
-            pl.BlockSpec((1, l), lambda bi: (bi, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda bi: (bi, 0)),
-            pl.BlockSpec((1, 1), lambda bi: (bi, 0)),
-        ],
+        grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, k), jnp.int32),
-            jax.ShapeDtypeStruct((b, 1), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, k), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(ctx_len.astype(jnp.int32).reshape(b, 1), tokens.astype(jnp.int32))
-    return out, cnt[:, 0]
+    )(ctx_len.astype(jnp.int32), shifted)
+    return out[:, 0], cnt[:, 0, 0]

@@ -7,6 +7,9 @@ wrappers, never the kernels directly.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -28,6 +31,40 @@ def on_tpu() -> bool:
     """Trace-time backend check the model layer uses to pick between the
     Pallas data plane and the XLA reference path."""
     return _on_tpu()
+
+
+# The serving plan whose mesh the kernel calls being traced run on (see
+# :func:`sharded_kernels`); None off-mesh.
+_PLAN: contextvars.ContextVar = contextvars.ContextVar("kernel_plan",
+                                                       default=None)
+
+
+@contextlib.contextmanager
+def sharded_kernels(plan):
+    """Trace the enclosed kernel calls per shard of ``plan.mesh``.
+
+    GSPMD cannot partition a Pallas call, so under a serving mesh every
+    kernel call runs inside a ``shard_map`` whose in/out specs come from
+    ``plan`` (:class:`repro.launch.sharding.ServeMeshPlan`, which also
+    lays out the pools): ``plan.paged_attention_specs(batch, kv_heads,
+    quant)`` and ``plan.ngram_specs(batch)``.  Each chip then sweeps its
+    own rows and KV heads of a pool that stays where the plan put it."""
+    token = _PLAN.set(plan)
+    try:
+        yield
+    finally:
+        _PLAN.reset(token)
+
+
+def _per_shard(fn, args: Tuple[jax.Array, ...], specs):
+    """``fn(*args)``, per shard of the active plan's mesh when there is
+    one; ``specs(plan) -> (in_specs, out_specs)``."""
+    plan = _PLAN.get()
+    if plan is None:
+        return fn(*args)
+    in_specs, out_specs = specs(plan)
+    return jax.shard_map(fn, mesh=plan.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 def ragged_attention(q: jax.Array, k_buf: jax.Array, v_buf: jax.Array,
@@ -53,10 +90,14 @@ def paged_ragged_attention(q: jax.Array, pool_k: jax.Array,
                            interpret: Optional[bool] = None) -> jax.Array:
     """Decode/verify attention straight off the block-paged KV pool."""
     if _on_tpu() or force_kernel:
-        return paged_ragged_verify_attention(
-            q, pool_k, pool_v, block_table, q_pos, kv_pos, window=window,
+        fn = functools.partial(
+            paged_ragged_verify_attention, window=window,
             interpret=bool(interpret) if interpret is not None
             else not _on_tpu())
+        return _per_shard(
+            fn, (q, pool_k, pool_v, block_table, q_pos, kv_pos),
+            lambda plan: plan.paged_attention_specs(
+                q.shape[0], pool_k.shape[2], quant=False))
     return ref.paged_ragged_verify_attention_ref(q, pool_k, pool_v,
                                                  block_table, q_pos, kv_pos,
                                                  window=window)
@@ -73,11 +114,15 @@ def paged_ragged_attention_quant(q: jax.Array, pool_k: jax.Array,
     """Decode/verify attention off the int8 block pool, dequantizing
     in-register inside the kv-sweep (DESIGN.md §13)."""
     if _on_tpu() or force_kernel:
-        return paged_ragged_verify_attention_quant(
-            q, pool_k, pool_v, k_scale, v_scale, block_table, q_pos,
-            kv_pos, window=window,
+        fn = functools.partial(
+            paged_ragged_verify_attention_quant, window=window,
             interpret=bool(interpret) if interpret is not None
             else not _on_tpu())
+        return _per_shard(
+            fn, (q, pool_k, pool_v, k_scale, v_scale, block_table, q_pos,
+                 kv_pos),
+            lambda plan: plan.paged_attention_specs(
+                q.shape[0], pool_k.shape[2], quant=True))
     return ref.paged_ragged_verify_attention_quant_ref(
         q, pool_k, pool_v, k_scale, v_scale, block_table, q_pos, kv_pos,
         window=window)
@@ -94,10 +139,12 @@ def ngram_propose(tokens: jax.Array, ctx_len: jax.Array, *, n: int, k: int,
         b = tokens.shape[0]
         return jnp.zeros((b, 0), jnp.int32), jnp.zeros((b,), jnp.int32)
     if _on_tpu() or force_kernel:
-        return ngram_suffix_propose(
-            tokens, ctx_len, n=n, k=k,
-            interpret=bool(interpret) if interpret is not None
-            else not _on_tpu())
+        interp = bool(interpret) if interpret is not None else not _on_tpu()
+        return _per_shard(
+            lambda tok, ctx: ngram_suffix_propose(tok, ctx, n=n, k=k,
+                                                  interpret=interp),
+            (tokens, ctx_len),
+            lambda plan: plan.ngram_specs(tokens.shape[0]))
     return ref.ngram_propose_ref(tokens, ctx_len, n=n, k=k)
 
 

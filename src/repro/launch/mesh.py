@@ -12,7 +12,7 @@ from typing import Optional, Tuple
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -20,25 +20,23 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     Multi-pod: 2x16x16 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    try:
-        return jax.make_mesh(shape, axes)
-    except ValueError:
-        # jax.make_mesh requires len(devices) == prod(shape); when running
-        # single-pod under the 512-device dry-run flag, take a prefix.
-        n = int(np.prod(shape))
-        devs = np.asarray(jax.devices()[:n]).reshape(shape)
-        return Mesh(devs, axes)
+    return make_mesh_from_shape(shape, axes)
 
 
 def make_mesh_from_shape(shape: Tuple[int, ...],
                          axes: Tuple[str, ...]) -> Mesh:
-    """Arbitrary mesh for tests (e.g. (1, 1) on the CPU container)."""
+    """A mesh over the first ``prod(shape)`` devices (e.g. (1, 1) on a
+    CPU host, or one pod under the 512-device dry-run flag).  Every axis
+    is ``Auto``: the sharding rules here are GSPMD layouts, not the
+    sharding-in-types that ``jax.make_mesh`` defaults to."""
+    auto = (AxisType.Auto,) * len(axes)
     try:
-        return jax.make_mesh(shape, axes)
+        return jax.make_mesh(shape, axes, axis_types=auto)
     except ValueError:
+        # jax.make_mesh requires len(devices) == prod(shape)
         n = int(np.prod(shape))
         devs = np.asarray(jax.devices()[:n]).reshape(shape)
-        return Mesh(devs, axes)
+        return Mesh(devs, axes, axis_types=auto)
 
 
 def single_device_mesh(axes: Tuple[str, ...] = ("data", "model")) -> Mesh:

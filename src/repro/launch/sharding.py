@@ -40,7 +40,8 @@ PyTree = Any
 
 def canonical_spec(*parts) -> P:
     """THE PartitionSpec constructor (speclint JX003): trims trailing
-    ``None`` dims so equal layouts are structurally equal.
+    ``None`` dims and writes a one-axis tuple ``('data',)`` as its bare
+    axis name ``'data'``, so equal layouts are structurally equal.
 
     Jit signatures compare PartitionSpecs *structurally* —
     ``P('data', None)`` and ``P('data')`` describe the same sharding but
@@ -50,7 +51,8 @@ def canonical_spec(*parts) -> P:
     Canonical form makes that hazard unrepresentable; every spec literal
     in the tree must be built here (trailing-``None`` literals anywhere
     else are JX003 findings)."""
-    out = list(parts)
+    out = [p[0] if isinstance(p, tuple) and len(p) == 1 else p
+           for p in parts]
     while out and out[-1] is None:
         out.pop()
     return P(*out)
@@ -298,6 +300,36 @@ class ServeMeshPlan:
     def cache_constraints(self, cache: PyTree) -> PyTree:
         return jax.lax.with_sharding_constraint(
             cache, serve_cache_shardings(cache, self.mesh, self.rules))
+
+    def _rows(self, batch: int):
+        data = tuple(self.rules.batch) if self.rules.batch else None
+        if data is None or batch % _axes_size(self.mesh, data):
+            return None
+        return data
+
+    def paged_attention_specs(self, batch: int, kv_heads: int, *,
+                              quant: bool):
+        """``shard_map`` (in_specs, out_spec) of a paged verify kernel
+        call (``kernels/ops.py``): ``q [B,T,H,D]``, the pools
+        ``[N,BS,KV,D]`` (+ int8 scales ``[N,BS,KV]``), ``block_table
+        [B,cols]``, ``q_pos [B,T]``, ``kv_pos [N,BS]``.  Rows go over
+        *data* when they divide it; KV heads, and the query heads they
+        serve, over *model* by :func:`kv_head_axis` — the rule that laid
+        out the pool, so each chip sweeps the heads it already holds and
+        no pool is gathered.  The block axis stays whole."""
+        rows = self._rows(batch)
+        heads = kv_head_axis(kv_heads, self.mesh, self.rules)
+        q = canonical_spec(rows, None, heads)
+        pool = canonical_spec(None, None, heads)
+        ctl = canonical_spec(rows)
+        pools = (pool,) * (4 if quant else 2)
+        return (q, *pools, ctl, ctl, P()), q
+
+    def ngram_specs(self, batch: int):
+        """``shard_map`` specs of the n-gram kernel: ``tokens [B,L]`` and
+        ``ctx_len [B]`` in, ``[B,K]`` / ``[B]`` out, rows over *data*."""
+        rows = canonical_spec(self._rows(batch))
+        return (rows, rows), (rows, rows)
 
 
 def moe_shardings(mesh: Mesh, rules: ShardingConfig):

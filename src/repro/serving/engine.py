@@ -34,6 +34,7 @@ TPU launch scripts drive; only meshes/shardings differ (repro/launch).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -48,6 +49,7 @@ from repro.core.config import (ModelConfig, ServingConfig, SpecDecodeConfig)
 from repro.core.drafters import build_drafter
 from repro.core.policies import build_policy
 from repro.core.sampling import sample_token
+from repro.kernels import ops as kernel_ops
 from repro.models import cache as cache_lib
 from repro.models.transformer import has_recurrent_state, model_specs
 from repro.serving.latency_model import RoundLatencyModel
@@ -201,19 +203,23 @@ class ServingEngine:
         b = serving.max_batch_size
         paged_arg = ((self.scheduler.kv_blocks_total(),
                       serving.kv_block_size) if self.paged else None)
-        self.state = sd.init_round_state(
-            cfg_target, cfg_draft, spec, b, serving.max_seq_len,
-            self.key, paged=paged_arg, drafter=drafter,
+        make_state = functools.partial(
+            sd.init_round_state, cfg_target, cfg_draft, spec, b,
+            serving.max_seq_len, self.key, paged=paged_arg, drafter=drafter,
             kv_quant=self.kv_quant)
         # --- serving mesh (DESIGN.md §5): place params + state, build the
         # per-bucket round jits with explicit in/out shardings ------------
         self.mesh = mesh
         self._plan = None
         self._mesh_round_fns: Dict[int, Any] = {}
-        if mesh is not None:
+        if mesh is None:
+            self.state = make_state()
+        else:
             from repro.launch import sharding as shd
             rules = shd.serve_rules(mesh, b)
             self._plan = shd.ServeMeshPlan(mesh=mesh, rules=rules)
+            # params built already sharded (launch/serve.py) stay where
+            # they are; device_put only moves host- or single-device trees
             self._pt_sh = shd.param_shardings(model_specs(cfg_target),
                                               mesh, rules)
             self.pt = jax.device_put(self.pt, self._pt_sh)
@@ -223,9 +229,12 @@ class ServingEngine:
                 self.pd = jax.device_put(self.pd, self._pd_sh)
             else:       # model-free drafter: no draft params to place
                 self._pd_sh = shd.replicated(mesh)
-            self._state_sh = shd.round_state_shardings(self.state, mesh,
-                                                       rules)
-            self.state = jax.device_put(self.state, self._state_sh)
+            # the round state (KV pools included) is born sharded: no
+            # device ever holds the whole pool
+            self._state_sh = shd.round_state_shardings(
+                jax.eval_shape(make_state), mesh, rules)
+            self.state = jax.jit(  # speclint: disable=JX004 (runs once)
+                make_state, out_shardings=self._state_sh)()
         # host-side mirror of state.sl_next, refreshed once per collect
         # while the round's other outputs are already being transferred —
         # the bucket choice never triggers its own device->host sync.
@@ -297,10 +306,13 @@ class ServingEngine:
             fn = _MESH_ROUND_JITS.get(key)
             if fn is None:
                 cfg_t, drafter, spec = self.cfg_t, self.drafter, self.spec
+                plan = self._plan
 
                 def body(pt, pd, state, active):
-                    return sd.spec_decode_round_impl(
-                        pt, pd, cfg_t, drafter, spec, k, state, active)
+                    # the Pallas calls run per shard, on the plan's specs
+                    with kernel_ops.sharded_kernels(plan):
+                        return sd.spec_decode_round_impl(
+                            pt, pd, cfg_t, drafter, spec, k, state, active)
                 rep = self._plan.replicated()
                 fn = jax.jit(body,
                              in_shardings=(self._pt_sh, self._pd_sh,
@@ -638,7 +650,8 @@ class ServingEngine:
         pipelined mode lags the device by one round): admission + batched
         prefill, the next round's bucket choice, and paged block growth
         under the staleness-slack invariant."""
-        self._admit()
+        with jax.default_matmul_precision(self.serving.matmul_precision):
+            self._admit()
         self._planned_k = None
         if self.scheduler.running:
             if self.serving.pipelined:
@@ -690,17 +703,15 @@ class ServingEngine:
              else self.policy.pick_bucket(self._host_context()))
         self._planned_k = None
         t_dispatch = time.monotonic()
-        self.state, out = self._round_fn(k)(self.state,
-                                            jnp.asarray(active_mask))
+        with jax.default_matmul_precision(self.serving.matmul_precision):
+            self.state, out = self._round_fn(k)(self.state,
+                                                jnp.asarray(active_mask))
         self.rounds += 1
         self.draft_steps += (k + 1) if k > 0 else 0
         sl_next = self.state.sl_next
         for arr in (out.emitted, out.num_emitted, out.num_accepted,
                     out.num_proposed, out.finished, out.live, sl_next):
-            try:
-                arr.copy_to_host_async()
-            except AttributeError:      # older jax / non-array leaf
-                pass
+            arr.copy_to_host_async()
         rec = _DispatchRecord(k=k, rows=rows, admits=self._pending_admits,
                               out=out, sl_next=sl_next,
                               t_dispatch=t_dispatch,

@@ -174,3 +174,33 @@ def test_recurrent_family_engine_exactness():
     req = Request(0, prompt=prompt, max_new_tokens=16)
     eng.run([req])
     assert req.output == ref
+
+
+@pytest.mark.parametrize("pipelined,precision",
+                         [(False, "high"), (True, "tensorfloat32")])
+def test_engine_traces_at_its_matmul_precision(small_pair, monkeypatch,
+                                               pipelined, precision):
+    """Every program the engine traces (prefill and rounds, target and
+    draft) runs its matmuls at ``ServingConfig.matmul_precision``; the
+    model itself sets none.  (Each case takes its own precision, so its
+    programs are traced afresh rather than found in jit's cache.)"""
+    from repro.models import transformer
+    cfg, pt, pd = small_pair
+    seen = []
+    lm_head = transformer._lm_head
+
+    def recording(*args, **kwargs):
+        seen.append(jax.config.jax_default_matmul_precision)
+        return lm_head(*args, **kwargs)
+
+    monkeypatch.setattr(transformer, "_lm_head", recording)
+    eng = ServingEngine(pt, cfg, pd, cfg, SpecDecodeConfig(),
+                        ServingConfig(max_batch_size=2, max_seq_len=64,
+                                      pipelined=pipelined,
+                                      matmul_precision=precision), seed=0)
+    eng.run([Request(i, prompt=[3, 4, 5, 6 + i], max_new_tokens=6)
+             for i in range(2)])
+    assert seen and set(seen) == {precision}
+    seen.clear()
+    transformer.forward(pt, cfg, jnp.asarray([[3, 4]], jnp.int32))
+    assert seen == [None]

@@ -39,6 +39,16 @@ requires_devices = pytest.mark.skipif(
     "device_count=4 (the CI multidevice lane sets it)")
 
 MESHES = ("1x4", "2x2")
+
+
+@pytest.fixture(autouse=True)
+def _drop_compiled_programs():
+    """With four forced host devices, one process compiling the whole
+    identity matrix crashed XLA's CPU compiler (JAX 0.9) about forty
+    tests in; dropping each test's compiled programs lets it finish."""
+    yield
+    if MULTI:
+        jax.clear_caches()
 ALL_POLICIES = tuple(available_policies())
 ALL_DRAFTERS = tuple(available_drafters())
 
@@ -97,7 +107,7 @@ def test_serve_cache_shardings_layout_contract():
              "kv_pos": jnp.zeros((4, 32), jnp.int32),
              "length": jnp.zeros((4,), jnp.int32)}
     shd = serve_cache_shardings(dense, mesh, rules)
-    assert shd["k"].spec[1] == ("data",)      # batch rows over data
+    assert shd["k"].spec[1] == "data"         # batch rows over data
     ngram = {"tokens": jnp.zeros((4, 64), jnp.int32),
              "length": jnp.zeros((4,), jnp.int32)}
     shn = serve_cache_shardings(ngram, mesh, rules)

@@ -3,10 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:   # offline container: deterministic fallback
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.signals import (KLDHistory, decay_weights, draft_entropy,
                                 kld_per_position, weighted_mean, weighted_var,

@@ -5,10 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:   # offline container: deterministic fallback
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.models.rglru import rglru_scan, rglru_step_scan, rglru_specs
 from repro.models.ssm import ssd_chunked, ssd_recurrent
