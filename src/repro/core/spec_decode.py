@@ -171,93 +171,107 @@ def spec_decode_round_impl(params_t: PyTree, params_d: PyTree,
 
     live = active & ~state.done
     sl_i = jnp.minimum(state.sl_next, k) * live.astype(jnp.int32)
-    k_acc = row_keys(state.key, state.seed, state.round_idx, PURPOSE_ACCEPT)
-    k_rec = row_keys(state.key, state.seed, state.round_idx, PURPOSE_RECOVER)
 
+    # Each stage runs under a named scope: metadata only (the ops and the
+    # kernels' names are the same without it), so a profile can charge
+    # every device operation of the round to the stage that issued it.
     # --- 1. propose ---------------------------------------------------------
-    if k > 0:
-        k_draft = row_keys(state.key, state.seed, state.round_idx,
-                           PURPOSE_DRAFT)
-        prop = drafter.propose(params_t, params_d, state.draft_cache,
-                               state.target_cache, state.pending, k, sl_i,
-                               policy, k_draft, live)
-        sl_i = jnp.minimum(sl_i, prop.eff_sl)  # early stop / short lookup
-        draft_tokens, drafted_cache = prop.tokens, prop.cache
-    else:  # no-draft bucket (autoregressive policy, or an all-idle batch)
-        draft_tokens = jnp.zeros((b, 0), jnp.int32)
-        drafted_cache = state.draft_cache
+    with jax.named_scope("propose"):
+        if k > 0:
+            k_draft = row_keys(state.key, state.seed, state.round_idx,
+                               PURPOSE_DRAFT)
+            prop = drafter.propose(params_t, params_d, state.draft_cache,
+                                   state.target_cache, state.pending, k,
+                                   sl_i, policy, k_draft, live)
+            sl_i = jnp.minimum(sl_i, prop.eff_sl)  # early stop / short lookup
+            draft_tokens, drafted_cache = prop.tokens, prop.cache
+        else:  # no-draft bucket (autoregressive policy, or an all-idle batch)
+            draft_tokens = jnp.zeros((b, 0), jnp.int32)
+            drafted_cache = state.draft_cache
 
-    # replace out-of-range draft positions by the reserved pad id so invalid
-    # token ids never propagate (paper §3.2); pad_id has a real (padded)
-    # embedding row and is masked out of every softmax.
-    pos = jnp.arange(k)[None, :]
-    proposed = pos < sl_i[:, None]
-    safe_drafts = jnp.where(proposed, draft_tokens, pad_id)
+        # replace out-of-range draft positions by the reserved pad id so
+        # invalid token ids never propagate (paper §3.2); pad_id has a real
+        # (padded) embedding row and is masked out of every softmax.
+        pos = jnp.arange(k)[None, :]
+        proposed = pos < sl_i[:, None]
+        safe_drafts = jnp.where(proposed, draft_tokens, pad_id)
 
     # --- 2. verification ----------------------------------------------------
-    verify_tokens = jnp.concatenate(
-        [state.pending[:, None], safe_drafts], axis=1)          # [B, K+1]
-    # paged caches: verification writes positions len..len+K; only
-    # j <= SL_i can ever be committed, so the rest never leaves the
-    # sequence's own block budget (dense rings ignore the mask)
-    verify_wm = (jnp.arange(k + 1)[None] <= sl_i[:, None]) & live[:, None]
-    t_logits, t_cache_v, _ = forward(params_t, cfg_t, verify_tokens,
-                                     cache=state.target_cache, mode="decode",
-                                     write_mask=verify_wm)
+    with jax.named_scope("verify"):
+        verify_tokens = jnp.concatenate(
+            [state.pending[:, None], safe_drafts], axis=1)      # [B, K+1]
+        # paged caches: verification writes positions len..len+K; only
+        # j <= SL_i can ever be committed, so the rest never leaves the
+        # sequence's own block budget (dense rings ignore the mask)
+        verify_wm = (jnp.arange(k + 1)[None] <= sl_i[:, None]) & live[:, None]
+        t_logits, t_cache_v, _ = forward(params_t, cfg_t, verify_tokens,
+                                         cache=state.target_cache,
+                                         mode="decode", write_mask=verify_wm)
 
     # --- 3. rejection sampling ----------------------------------------------
-    if k > 0:
-        dl = _match_vocab(prop.logits, t_logits.shape[-1])
-    else:
-        dl = jnp.zeros((b, 0) + t_logits.shape[-1:], t_logits.dtype)
-    rej: RejectionResult = rejection_sample(
-        state.key, safe_drafts, dl, t_logits, sl_i,
-        temperature=spec.temperature, vocab_size=cfg_t.vocab_size,
-        pad_id=pad_id, row_keys=(k_acc, k_rec))
+    with jax.named_scope("reject"):
+        k_acc = row_keys(state.key, state.seed, state.round_idx,
+                         PURPOSE_ACCEPT)
+        k_rec = row_keys(state.key, state.seed, state.round_idx,
+                         PURPOSE_RECOVER)
+        if k > 0:
+            dl = _match_vocab(prop.logits, t_logits.shape[-1])
+        else:
+            dl = jnp.zeros((b, 0) + t_logits.shape[-1:], t_logits.dtype)
+        rej: RejectionResult = rejection_sample(
+            state.key, safe_drafts, dl, t_logits, sl_i,
+            temperature=spec.temperature, vocab_size=cfg_t.vocab_size,
+            pad_id=pad_id, row_keys=(k_acc, k_rec))
 
     # --- 4. post-hoc signals --------------------------------------------------
-    if k > 0:
-        kld = drafter.observation_kld(t_logits[:, :k], dl, safe_drafts,
-                                      proposed)                 # [B, K]
-    else:
-        kld = jnp.zeros((b, 0), jnp.float32)
-    obs = PolicyObservation(
-        kld=kld, proposed_valid=proposed, num_accepted=rej.num_accepted,
-        num_proposed=sl_i, active=live)
-    new_pstate = policy.observe(state.policy_state, obs)
+    with jax.named_scope("signal"):
+        if k > 0:
+            kld = drafter.observation_kld(t_logits[:, :k], dl, safe_drafts,
+                                          proposed)             # [B, K]
+        else:
+            kld = jnp.zeros((b, 0), jnp.float32)
+        obs = PolicyObservation(
+            kld=kld, proposed_valid=proposed, num_accepted=rej.num_accepted,
+            num_proposed=sl_i, active=live)
+        new_pstate = policy.observe(state.policy_state, obs)
 
     # --- 5. commit ------------------------------------------------------------
-    n_committed = (1 + rej.num_accepted) * live.astype(jnp.int32)
-    t_cache = commit(params_t, cfg_t, verify_tokens, state.target_cache,
-                     t_cache_v, n_committed)
-    if k > 0:
-        d_cache = drafter.commit(params_d, verify_tokens, state.draft_cache,
-                                 drafted_cache, n_committed)
-    else:  # the drafter was never consulted
-        d_cache = state.draft_cache
+    with jax.named_scope("commit"):
+        n_committed = (1 + rej.num_accepted) * live.astype(jnp.int32)
+        t_cache = commit(params_t, cfg_t, verify_tokens, state.target_cache,
+                         t_cache_v, n_committed)
+        if k > 0:
+            d_cache = drafter.commit(params_d, verify_tokens,
+                                     state.draft_cache, drafted_cache,
+                                     n_committed)
+        else:  # the drafter was never consulted
+            d_cache = state.draft_cache
 
-    # --- 6. device-side termination -------------------------------------------
-    # Truncate the emitted stream exactly the way the host loop used to:
-    # walk the tokens in order, stop after the first EOS or once the
-    # remaining ``tokens_budget`` is spent, and raise ``done`` so later
-    # rounds skip the slot.  The host merely mirrors these decisions at
-    # reconciliation — which may be a full round later.
-    n_raw = rej.num_emitted                                    # [B]
-    pos1 = jnp.arange(k + 1)[None, :]
-    in_raw = pos1 < n_raw[:, None]
-    is_eos = ((rej.emitted == state.eos_id[:, None])
-              & in_raw & (state.eos_id >= 0)[:, None])
-    inf = jnp.int32(k + 2)                                     # > any n_raw
-    eos_cut = jnp.where(is_eos.any(1),
-                        jnp.argmax(is_eos, 1).astype(jnp.int32) + 1, inf)
-    n_emit = jnp.minimum(n_raw, jnp.minimum(eos_cut, state.tokens_budget))
-    n_emit = jnp.where(live, n_emit, 0)
-    finished = live & ((n_emit == eos_cut) | (n_emit == state.tokens_budget))
-    new_done = state.done | finished
-    new_budget = jnp.maximum(state.tokens_budget - n_emit, 0)
+        # device-side termination: truncate the emitted stream exactly the
+        # way the host loop used to: walk the tokens in order, stop after
+        # the first EOS or once the remaining ``tokens_budget`` is spent,
+        # and raise ``done`` so later rounds skip the slot.  The host
+        # merely mirrors these decisions at reconciliation — which may be
+        # a full round later.
+        n_raw = rej.num_emitted                                # [B]
+        pos1 = jnp.arange(k + 1)[None, :]
+        in_raw = pos1 < n_raw[:, None]
+        is_eos = ((rej.emitted == state.eos_id[:, None])
+                  & in_raw & (state.eos_id >= 0)[:, None])
+        inf = jnp.int32(k + 2)                                 # > any n_raw
+        eos_cut = jnp.where(is_eos.any(1),
+                            jnp.argmax(is_eos, 1).astype(jnp.int32) + 1, inf)
+        n_emit = jnp.minimum(n_raw, jnp.minimum(eos_cut,
+                                                state.tokens_budget))
+        n_emit = jnp.where(live, n_emit, 0)
+        finished = live & ((n_emit == eos_cut)
+                           | (n_emit == state.tokens_budget))
+        new_done = state.done | finished
+        new_budget = jnp.maximum(state.tokens_budget - n_emit, 0)
 
-    # --- 7. predict next SL ----------------------------------------------------
-    sl_next, new_pstate, telemetry = policy.predict(new_pstate, live)
+    # --- 6. predict next SL ----------------------------------------------------
+    with jax.named_scope("predict"):
+        sl_next, new_pstate, telemetry = policy.predict(new_pstate, live)
 
     new_state = RoundState(
         target_cache=t_cache, draft_cache=d_cache, policy_state=new_pstate,

@@ -28,6 +28,18 @@ Two execution modes share every phase:
     on the device); scheduling-side telemetry (round counts, bucket
     sequence) may differ by the one-round lag.
 
+Every phase runs under a profiler span (``jax.profiler.TraceAnnotation``,
+which costs a flag check while no profile is being taken): each
+``pump()`` is a step ``engine.round`` numbered by the round it
+dispatches, holding ``engine.plan`` (``engine.admit`` ⊃
+``engine.prefill`` per prefill group, ``engine.pick_bucket``,
+``engine.plan_blocks`` ⊃ ``engine.block_sync``), ``engine.dispatch``
+(``engine.round_call``) and ``engine.collect`` (``engine.collect_wait``,
+``engine.reconcile``, ``engine.block_sync``).  The round log books each
+phase's thread CPU seconds (``plan_cpu_s``, ``dispatch_cpu_s``,
+``collect_cpu_s``) to the round it served, and names the round by the
+same ordinal (``round``).
+
 This runs for real on CPU (reduced models) and is the same code path the
 TPU launch scripts drive; only meshes/shardings differ (repro/launch).
 """
@@ -37,7 +49,8 @@ import dataclasses
 import functools
 import math
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +70,8 @@ from repro.serving.request import Request, RequestState
 from repro.serving.scheduler import LookaheadScheduler
 
 PyTree = Any
+
+_span = jax.profiler.TraceAnnotation
 
 # Mesh-path round programs, shared ACROSS engine instances: keyed by the
 # exact trace identity (model/drafter/spec/bucket) plus the serving-mesh
@@ -88,11 +103,12 @@ class _DispatchRecord:
     """
 
     __slots__ = ("k", "rows", "admits", "out", "sl_next", "t_dispatch",
-                 "prefill_tokens")
+                 "prefill_tokens", "ordinal", "plan_cpu_s", "dispatch_cpu_s")
 
     def __init__(self, k: int, rows, admits, out, sl_next, t_dispatch,
-                 prefill_tokens=0):
+                 prefill_tokens=0, ordinal=0, plan_cpu_s=0.0):
         self.k = k
+        self.ordinal = ordinal    # the engine's count of earlier dispatches
         self.rows = rows          # [(req, slot, preemptions-at-dispatch)]
         self.admits = admits      # [(fresh_reqs, pend [R] jax, fresh_idx,
                                   #   preemptions-at-prefill)]
@@ -102,6 +118,21 @@ class _DispatchRecord:
         # prefill tokens computed by the admission wave riding this
         # round's wall interval (the latency model's c_prefill regressor)
         self.prefill_tokens = prefill_tokens
+        # thread CPU seconds of the plan and dispatch that served it
+        self.plan_cpu_s = plan_cpu_s
+        self.dispatch_cpu_s = 0.0
+
+
+class _RoundHost(NamedTuple):
+    """A collected round's outputs, copied to the host."""
+    emitted: np.ndarray
+    n_emit: np.ndarray
+    n_acc: np.ndarray
+    n_prop: np.ndarray
+    fin: np.ndarray
+    live: np.ndarray
+    sl_next: np.ndarray
+    admit_pends: List[np.ndarray]
 
 
 class ServingEngine:
@@ -254,6 +285,9 @@ class ServingEngine:
         # into each dispatch record as the latency model's c_prefill
         # regressor for the round interval they ride
         self._prefill_tokens_pending = 0
+        # thread CPU seconds of plans since the last dispatch, booked to
+        # the round that dispatch starts
+        self._plan_cpu_pending = 0.0
         # telemetry
         self.rounds = 0
         self.draft_steps = 0            # padded bucket steps (k+1)
@@ -336,20 +370,22 @@ class ServingEngine:
         rewrite the affected block-table rows."""
         if not rows and not fresh_ids:
             return
-        st = self.state
-        mirror = self.drafter.mirrors_kv()
-        tc = dict(st.target_cache)
-        dc = dict(st.draft_cache) if mirror else st.draft_cache
-        if fresh_ids:
-            tc["kv_pos"] = cache_lib.reset_blocks(tc["kv_pos"], fresh_ids)
-            if mirror:
-                dc["kv_pos"] = cache_lib.reset_blocks(dc["kv_pos"], fresh_ids)
-        for slot, row in rows:
-            r = jnp.asarray(row, jnp.int32)
-            tc["block_table"] = tc["block_table"].at[slot].set(r)
-            if mirror:
-                dc["block_table"] = dc["block_table"].at[slot].set(r)
-        self.state = st._replace(target_cache=tc, draft_cache=dc)
+        with _span("engine.block_sync", rows=len(rows), fresh=len(fresh_ids)):
+            st = self.state
+            mirror = self.drafter.mirrors_kv()
+            tc = dict(st.target_cache)
+            dc = dict(st.draft_cache) if mirror else st.draft_cache
+            if fresh_ids:
+                tc["kv_pos"] = cache_lib.reset_blocks(tc["kv_pos"], fresh_ids)
+                if mirror:
+                    dc["kv_pos"] = cache_lib.reset_blocks(dc["kv_pos"],
+                                                          fresh_ids)
+            for slot, row in rows:
+                r = jnp.asarray(row, jnp.int32)
+                tc["block_table"] = tc["block_table"].at[slot].set(r)
+                if mirror:
+                    dc["block_table"] = dc["block_table"].at[slot].set(r)
+            self.state = st._replace(target_cache=tc, draft_cache=dc)
 
     def _plan_blocks(self) -> None:
         """Pre-round capacity planning: grow every running sequence's
@@ -453,7 +489,10 @@ class ServingEngine:
             b = _bucket(n, cap=self.serving.max_seq_len)
             groups.setdefault((warm, b), []).append(req)
         for warm, bucket in sorted(groups):
-            self._prefill_group(groups[(warm, bucket)], bucket, warm=warm)
+            reqs = groups[(warm, bucket)]
+            with _span("engine.prefill", rows=len(reqs), bucket=bucket,
+                       warm=warm):
+                self._prefill_group(reqs, bucket, warm=warm)
 
     def _prefill_group(self, reqs: List[Request], bucket: int,
                        warm: bool = False) -> None:
@@ -650,22 +689,29 @@ class ServingEngine:
         pipelined mode lags the device by one round): admission + batched
         prefill, the next round's bucket choice, and paged block growth
         under the staleness-slack invariant."""
-        with jax.default_matmul_precision(self.serving.matmul_precision):
-            self._admit()
-        self._planned_k = None
-        if self.scheduler.running:
-            if self.serving.pipelined:
-                self._planned_k = self._pick_bucket_pipelined()
-            if self.paged:
-                before = self.scheduler.preempted_total
-                self._plan_blocks()         # may preempt (slots go inactive)
-                if (self.serving.pipelined and self.scheduler.running
-                        and self.scheduler.preempted_total != before):
-                    # an evicted slot must not size the bucket: re-pick
-                    # over the survivors.  A smaller K only shrinks
-                    # write extents, so the block growth just planned
-                    # (with the wider K) still over-covers.
-                    self._planned_k = self._pick_bucket_pipelined()
+        t_cpu = time.thread_time()
+        with _span("engine.plan"):
+            with _span("engine.admit"), jax.default_matmul_precision(
+                    self.serving.matmul_precision):
+                self._admit()
+            self._planned_k = None
+            if self.scheduler.running:
+                if self.serving.pipelined:
+                    with _span("engine.pick_bucket"):
+                        self._planned_k = self._pick_bucket_pipelined()
+                if self.paged:
+                    before = self.scheduler.preempted_total
+                    with _span("engine.plan_blocks"):
+                        self._plan_blocks()   # may preempt (slots go inactive)
+                    if (self.serving.pipelined and self.scheduler.running
+                            and self.scheduler.preempted_total != before):
+                        # an evicted slot must not size the bucket: re-pick
+                        # over the survivors.  A smaller K only shrinks
+                        # write extents, so the block growth just planned
+                        # (with the wider K) still over-covers.
+                        with _span("engine.pick_bucket"):
+                            self._planned_k = self._pick_bucket_pipelined()
+        self._plan_cpu_pending += time.thread_time() - t_cpu
 
     def _pick_bucket_pipelined(self) -> int:
         """Bucket choice for a pipelined dispatch, whose SL mirror is one
@@ -697,28 +743,38 @@ class ServingEngine:
         if not self.scheduler.running:
             assert not self._pending_admits
             return None
+        t_cpu = time.thread_time()
         rows = [(r, r.slot, r.preemptions) for r in self.scheduler.running]
         active_mask = self.scheduler.active_mask
-        k = (self._planned_k if self._planned_k is not None
-             else self.policy.pick_bucket(self._host_context()))
+        k = self._planned_k
+        if k is None:
+            with _span("engine.pick_bucket"):
+                k = self.policy.pick_bucket(self._host_context())
         self._planned_k = None
-        t_dispatch = time.monotonic()
-        with jax.default_matmul_precision(self.serving.matmul_precision):
-            self.state, out = self._round_fn(k)(self.state,
-                                                jnp.asarray(active_mask))
-        self.rounds += 1
-        self.draft_steps += (k + 1) if k > 0 else 0
-        sl_next = self.state.sl_next
-        for arr in (out.emitted, out.num_emitted, out.num_accepted,
-                    out.num_proposed, out.finished, out.live, sl_next):
-            arr.copy_to_host_async()
-        rec = _DispatchRecord(k=k, rows=rows, admits=self._pending_admits,
-                              out=out, sl_next=sl_next,
-                              t_dispatch=t_dispatch,
-                              prefill_tokens=self._prefill_tokens_pending)
+        ordinal = self.rounds
+        with _span("engine.dispatch", round=ordinal, k=k):
+            t_dispatch = time.monotonic()
+            with _span("engine.round_call"), jax.default_matmul_precision(
+                    self.serving.matmul_precision):
+                self.state, out = self._round_fn(k)(self.state,
+                                                    jnp.asarray(active_mask))
+                sl_next = self.state.sl_next
+                for arr in (out.emitted, out.num_emitted, out.num_accepted,
+                            out.num_proposed, out.finished, out.live,
+                            sl_next):
+                    arr.copy_to_host_async()
+            self.rounds += 1
+            self.draft_steps += (k + 1) if k > 0 else 0
+            rec = _DispatchRecord(
+                k=k, rows=rows, admits=self._pending_admits, out=out,
+                sl_next=sl_next, t_dispatch=t_dispatch,
+                prefill_tokens=self._prefill_tokens_pending, ordinal=ordinal,
+                plan_cpu_s=self._plan_cpu_pending)
         self._prefill_tokens_pending = 0
+        self._plan_cpu_pending = 0.0
         self._pending_admits = []
         self._inflight = rec
+        rec.dispatch_cpu_s = time.thread_time() - t_cpu
         return rec
 
     def collect(self, rec: _DispatchRecord) -> List[Request]:
@@ -729,16 +785,36 @@ class ServingEngine:
         refresh, shrink-to-committed — on the host.  In pipelined mode
         this runs while the NEXT round is already executing, so shrink
         keeps the in-flight round's write extent resident."""
-        t0 = time.monotonic()
-        emitted = np.asarray(rec.out.emitted)
-        n_emit = np.asarray(rec.out.num_emitted)
-        n_acc = np.asarray(rec.out.num_accepted)
-        n_prop = np.asarray(rec.out.num_proposed)
-        fin = np.asarray(rec.out.finished)
-        live = np.asarray(rec.out.live)
-        sl_next = np.array(rec.sl_next)     # writable copy
-        admit_pends = [np.asarray(p) for _, p, _, _ in rec.admits]
-        host_blocked = time.monotonic() - t0
+        t_cpu = time.thread_time()
+        with _span("engine.collect", round=rec.ordinal, k=rec.k):
+            t0 = time.monotonic()
+            with _span("engine.collect_wait"):
+                host = _RoundHost(
+                    emitted=np.asarray(rec.out.emitted),
+                    n_emit=np.asarray(rec.out.num_emitted),
+                    n_acc=np.asarray(rec.out.num_accepted),
+                    n_prop=np.asarray(rec.out.num_proposed),
+                    fin=np.asarray(rec.out.finished),
+                    live=np.asarray(rec.out.live),
+                    sl_next=np.array(rec.sl_next),      # writable copy
+                    admit_pends=[np.asarray(p) for _, p, _, _ in rec.admits])
+            host_blocked = time.monotonic() - t0
+            with _span("engine.reconcile"):
+                finished, shrunk_rows = self._reconcile(rec, host)
+            if shrunk_rows:
+                self._sync_block_tables(shrunk_rows, [])
+            self._log_round(rec, host, host_blocked, t_cpu)
+        if self._inflight is rec:
+            self._inflight = None
+        return finished
+
+    def _reconcile(self, rec: _DispatchRecord, host: "_RoundHost"
+                   ) -> Tuple[List[Request], List[Tuple[int, np.ndarray]]]:
+        """Mirror a collected round's device decisions on the host; returns
+        the requests it finished and the block-table rows its shrink
+        changed."""
+        emitted, n_emit, n_acc = host.emitted, host.n_emit, host.n_acc
+        n_prop, fin, live = host.n_prop, host.fin, host.live
         # refresh the SL mirror only for slots STILL OWNED by the request
         # the round ran: a slot re-admitted at this iteration's plan (or
         # preempted) already carries its new occupant's initial SL, which
@@ -746,7 +822,7 @@ class ServingEngine:
         # clobber
         for req, slot, _ in rec.rows:
             if self.scheduler.slots[slot] is req:
-                self._sl_next_host[slot] = sl_next[slot]
+                self._sl_next_host[slot] = host.sl_next[slot]
         self.scheduler.update_predictions(self._sl_next_host)
         now = time.monotonic()
         finished: List[Request] = []
@@ -760,8 +836,8 @@ class ServingEngine:
         # queue, not released — release would no-op on the empty slot
         # and the FINISHED request would be readmitted as a zombie.
         in_rows = {id(r) for r, _, _ in rec.rows}
-        for (fresh_reqs, _, fresh_idx, pcounts), pend_np in zip(rec.admits,
-                                                                admit_pends):
+        for (fresh_reqs, _, fresh_idx, pcounts), pend_np in zip(
+                rec.admits, host.admit_pends):
             items = [(req, int(pend_np[i]), pc)
                      for req, i, pc in zip(fresh_reqs, fresh_idx, pcounts)
                      if id(req) in in_rows]
@@ -835,12 +911,19 @@ class ServingEngine:
                                  self.serving.max_seq_len))
                 if self.scheduler.shrink_to(req, keep):
                     shrunk_rows.append((req.slot, self._table_row(req)))
-        if shrunk_rows:
-            self._sync_block_tables(shrunk_rows, [])
+        return finished, shrunk_rows
+
+    def _log_round(self, rec: _DispatchRecord, host: "_RoundHost",
+                   host_blocked: float, t_cpu: float) -> None:
+        """Append a collected round's record to the round log and feed the
+        latency model.  ``t_cpu`` is the thread clock at collect's start."""
+        n_emit, n_acc, n_prop, live = (host.n_emit, host.n_acc, host.n_prop,
+                                       host.live)
         # (c) round log — emitted/accepted/proposed all masked by the
         # SAME per-round live-row set (slots that did real work), and
         # draft_steps_effective takes its max over that set too
         round_rec = {
+            "round": rec.ordinal,
             "k": rec.k,
             "drafter": self.spec.drafter,
             "emitted": float(n_emit[live].sum()),
@@ -856,12 +939,6 @@ class ServingEngine:
         # model-free drafters' wins visible in benchmark rows
         round_rec["draft_cost_effective"] = (eff_steps
                                              * self.drafter.step_cost())
-        # per-sequence KV slots the policy plans for the NEXT round — the
-        # capacity-planning view of intra-batch heterogeneity.  Logged
-        # after release so just-finished slots are not counted.
-        round_rec["lookahead"] = float(
-            self.scheduler.lookahead_slots()[self.scheduler.active_mask]
-            .sum())
         round_rec["kv_blocks_in_use"] = float(
             self.scheduler.kv_blocks_in_use())
         round_rec["kv_pool_utilization"] = (
@@ -899,19 +976,18 @@ class ServingEngine:
             round_rec["wall_s"] = self._inflight.t_dispatch - rec.t_dispatch
         else:
             round_rec["wall_s"] = time.monotonic() - rec.t_dispatch
-        # latency-model regressors + prediction-before-update, then fold
-        # the measured wall in (one RLS sample per round, DESIGN.md §15)
+        # the latency model's regressors, then one RLS sample per round
+        # (DESIGN.md §15)
         b_eff = len(rec.rows)
         round_rec["b_eff"] = float(b_eff)
         round_rec["prefill_tokens"] = float(rec.prefill_tokens)
-        round_rec["t_round_pred_s"] = self.latency_model.predict_round_s(
-            rec.k, b_eff, rec.prefill_tokens)
         self.latency_model.observe(round_rec["wall_s"], rec.k, b_eff,
                                    rec.prefill_tokens)
+        # thread CPU seconds of the three phases that served this round
+        round_rec["plan_cpu_s"] = rec.plan_cpu_s
+        round_rec["dispatch_cpu_s"] = rec.dispatch_cpu_s
+        round_rec["collect_cpu_s"] = time.thread_time() - t_cpu
         self.round_log.append(round_rec)
-        if self._inflight is rec:
-            self._inflight = None
-        return finished
 
     # ------------------------------------------------------------------ step
     def step(self) -> List[Request]:
@@ -944,17 +1020,21 @@ class ServingEngine:
         plans + dispatches round N+1, then reconciles round N while N+1
         executes on device.  Returns requests that reached a terminal
         state this iteration; when ``has_pending_work()`` goes false the
-        driver must ``drain()`` the final in-flight round."""
-        if not self.serving.pipelined:
-            return self.step() if self.scheduler.has_work() else []
-        done: List[Request] = []
-        self.plan()
-        done += self.scheduler.pop_rejected()
-        prev = self._inflight
-        self.dispatch()
-        if prev is not None:
-            done += self.collect(prev)
-        return done
+        driver must ``drain()`` the final in-flight round.  The iteration
+        is the profiler step ``engine.round``, numbered by the ordinal of
+        the round it dispatches."""
+        with jax.profiler.StepTraceAnnotation("engine.round",
+                                              step_num=self.rounds):
+            if not self.serving.pipelined:
+                return self.step() if self.scheduler.has_work() else []
+            done: List[Request] = []
+            self.plan()
+            done += self.scheduler.pop_rejected()
+            prev = self._inflight
+            self.dispatch()
+            if prev is not None:
+                done += self.collect(prev)
+            return done
 
     def drain(self) -> List[Request]:
         """Reconcile the last in-flight round after the final ``pump()``
