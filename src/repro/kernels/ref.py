@@ -62,9 +62,9 @@ def paged_ragged_verify_attention_quant_ref(
         q_pos: jax.Array, kv_pos: jax.Array,
         window: Optional[int] = None) -> jax.Array:
     """Oracle for the quantized block-paged kernel: gather the int8 view
-    and its scales through the block table, dequantize in f32 (the same
-    ``int8 * scale`` product the kernel fuses in-register), then run the
-    dense oracle.
+    and its scales through the block table, dequantize in f32 (``int8 *
+    scale``; the kernel applies the same scales to each slot's score and
+    probability, equal up to f32 rounding), then run the dense oracle.
 
     pool_k/pool_v [N, BS, KV, D] int8; k_scale/v_scale [N, BS, KV] fp32;
     block_table [B, MAXB] (-1 = unallocated); kv_pos [N, BS]."""

@@ -189,8 +189,9 @@ def quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 def dequantize_kv(q: jax.Array, scale: jax.Array) -> jax.Array:
     """Inverse of :func:`quantize_kv`: [..., KV, D] int8 + [..., KV]
-    scales -> f32.  The Pallas kv-sweep fuses exactly this product
-    in-register; this jnp form is the oracle's and the XLA fallback's."""
+    scales -> f32.  The Pallas kv-sweep applies the same scales
+    in-register, to each slot's score and probability; this jnp form is
+    the oracle's and the XLA fallback's."""
     return q.astype(jnp.float32) * scale[..., None]
 
 

@@ -257,8 +257,9 @@ def _attn_sublayer(p: dict, cfg: ModelConfig, x: jax.Array, *,
             positions, block_table, keep=write_mask)
         new_bufs = (k_buf, v_buf, ks_buf, vs_buf)
         if kv_pos_pool is not None and kernel_ops.on_tpu():
-            # TPU data plane: int8 tiles + scale columns stream through
-            # the table lookup and dequantize in-register in the sweep
+            # TPU data plane: int8 pages stream through the table
+            # lookup, their per-slot scales applied in-register in the
+            # sweep
             out = kernel_ops.paged_ragged_attention_quant(
                 q, k_buf, v_buf, ks_buf, vs_buf, block_table, positions,
                 kv_pos_pool, window=window)

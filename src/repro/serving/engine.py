@@ -63,6 +63,7 @@ from repro.core.drafters import build_drafter
 from repro.core.policies import build_policy
 from repro.core.sampling import sample_token
 from repro.kernels import ops as kernel_ops
+from repro.kernels.ragged_attention import live_blocks
 from repro.models import cache as cache_lib
 from repro.models.transformer import has_recurrent_state, model_specs
 from repro.serving.latency_model import RoundLatencyModel
@@ -799,14 +800,25 @@ class ServingEngine:
                     sl_next=np.array(rec.sl_next),      # writable copy
                     admit_pends=[np.asarray(p) for _, p, _, _ in rec.admits])
             host_blocked = time.monotonic() - t0
+            swept = self._verify_blocks_swept(rec) if self.paged else None
             with _span("engine.reconcile"):
                 finished, shrunk_rows = self._reconcile(rec, host)
             if shrunk_rows:
                 self._sync_block_tables(shrunk_rows, [])
-            self._log_round(rec, host, host_blocked, t_cpu)
+            self._log_round(rec, host, host_blocked, t_cpu, swept)
         if self._inflight is rec:
             self._inflight = None
         return finished
+
+    def _verify_blocks_swept(self, rec: _DispatchRecord) -> int:
+        """Block-table columns the round's paged verify sweep read over
+        the rows it carried: :func:`live_blocks` of each row's last query
+        position, its committed length before the round plus K.  Called
+        before the round's commit moves those lengths."""
+        last = np.array([req.cache_len + rec.k for req, _, _ in rec.rows],
+                        np.int64)
+        return int(live_blocks(last, self.serving.kv_block_size,
+                               self.serving.blocks_per_seq()).sum())
 
     def _reconcile(self, rec: _DispatchRecord, host: "_RoundHost"
                    ) -> Tuple[List[Request], List[Tuple[int, np.ndarray]]]:
@@ -914,9 +926,11 @@ class ServingEngine:
         return finished, shrunk_rows
 
     def _log_round(self, rec: _DispatchRecord, host: "_RoundHost",
-                   host_blocked: float, t_cpu: float) -> None:
+                   host_blocked: float, t_cpu: float,
+                   swept: Optional[int]) -> None:
         """Append a collected round's record to the round log and feed the
-        latency model.  ``t_cpu`` is the thread clock at collect's start."""
+        latency model.  ``t_cpu`` is the thread clock at collect's start;
+        ``swept`` the paged verify sweep's blocks (None off the pool)."""
         n_emit, n_acc, n_prop, live = (host.n_emit, host.n_acc, host.n_prop,
                                        host.live)
         # (c) round log — emitted/accepted/proposed all masked by the
@@ -944,6 +958,8 @@ class ServingEngine:
         round_rec["kv_pool_utilization"] = (
             round_rec["kv_blocks_in_use"]
             / max(self.scheduler.kv_blocks_total(), 1))
+        if swept is not None:
+            round_rec["verify_blocks_swept"] = float(swept)
         # draft-side KV residency: the mirrored pool holds exactly the
         # target's in-use block set; a model-free drafter holds none —
         # the capacity win of lookup/self drafting, made visible per round

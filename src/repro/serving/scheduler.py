@@ -22,7 +22,8 @@ Two admission regimes share that planning:
   admission is worst-case reservation: a free slot IS the budget.
 * **paged** (``paged_kv=True``) — a :class:`BlockAllocator` owns a free
   list over the shared block pool.  Admission charges only the blocks the
-  prefill actually needs; each round the engine asks
+  prefill actually needs, and admits only while two free blocks remain
+  for every running sequence; each round the engine asks
   :meth:`ensure_capacity` to grow a sequence to ``committed + SL_i + 1``
   tokens (``policy.lookahead``), and when the pool runs dry the youngest
   running request is **preempted** — its blocks return to the pool and it
@@ -406,6 +407,17 @@ class LookaheadScheduler:
                 return False
         return True
 
+    def _leaves_headroom(self, need: int) -> bool:
+        """Whether taking ``need`` blocks leaves two free blocks for every
+        running sequence: growth room for the whole batch over ``2 *
+        block_size`` tokens a row before any sequence has to finish.
+        Admitting into a pool with less hands the next rounds' growth to
+        preemption, and LIFO preemption then evicts the sequence just
+        admitted and recomputes it, round after round.  With nothing
+        running the whole pool is available."""
+        running = sum(r is not None for r in self.slots)
+        return self.allocator.n_free - need >= 2 * running
+
     def free_slots(self) -> List[int]:
         return [i for i, r in enumerate(self.slots) if r is None]
 
@@ -492,9 +504,10 @@ class LookaheadScheduler:
         Dense: a free slot is a full max_seq_len reservation.  Paged: the
         request is also charged ``ceil(prefill_len / block_size)`` pool
         blocks up front; if the pool cannot cover the next request's
-        prefill it stays queued (preemption during the round, not
-        admission, resolves sustained pressure).  Infeasible (oversize)
-        requests become ``REJECTED`` and are drained via
+        prefill and still keep two free blocks for every running sequence
+        (:meth:`_leaves_headroom`) it stays queued (preemption during the
+        round, not admission, resolves sustained pressure).  Infeasible
+        (oversize) requests become ``REJECTED`` and are drained via
         :meth:`pop_rejected`.
 
         SLO gate (DESIGN.md §15): alongside the block-budget ``_fits``
@@ -574,7 +587,8 @@ class LookaheadScheduler:
                 # device program order keeps the copy ahead of any later
                 # owner's reset.
                 self.allocator.acquire(covered_ids)
-                fresh = self.allocator.alloc(need)
+                fresh = (self.allocator.alloc(need)
+                         if self._leaves_headroom(need) else None)
                 if fresh is None:
                     self.allocator.free(covered_ids)
                     if not any(r is not None for r in self.slots):

@@ -151,10 +151,14 @@ def test_paged_dispatch_under_mesh_matches_oracle(monkeypatch, quant,
     monkeypatch.setattr(ops, "_on_tpu", lambda: False)
     rng = np.random.RandomState(3)
     b, t, h, kv, d, n, bs, maxb = 2, 3, 4, 2, 32, 8, 8, 4
-    table = jnp.asarray(np.stack([rng.permutation(n)[:maxb]] * b), jnp.int32)
+    perm = rng.permutation(n)[:maxb]
+    table = jnp.asarray(np.stack([perm] * b), jnp.int32)
     qpos = jnp.asarray(np.tile(np.arange(20, 20 + t), (b, 1)), jnp.int32)
-    kvp = jnp.asarray(np.where(rng.rand(n, bs) < 0.8,
-                               rng.randint(0, 23, (n, bs)), -1), jnp.int32)
+    # position p sits in logical block p // bs at slot p % bs, as the
+    # pool's writes leave it; a fifth of the slots are empty
+    pos = np.full((n, bs), -1)
+    pos[perm] = np.arange(maxb * bs).reshape(maxb, bs)
+    kvp = jnp.asarray(np.where(rng.rand(n, bs) < 0.8, pos, -1), jnp.int32)
     q = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
     if quant:
         pools = [jnp.asarray(rng.randint(-127, 128, (n, bs, kv, d)), jnp.int8)

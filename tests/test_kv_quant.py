@@ -27,6 +27,8 @@ from repro.serving.engine import ServingEngine
 from repro.serving.request import Request
 from repro.serving.scheduler import LookaheadScheduler
 
+from paged_inputs import paged_attn_inputs
+
 jax.config.update("jax_platform_name", "cpu")
 
 
@@ -171,48 +173,39 @@ def test_copy_scales_mirrors_copy_blocks():
 # ---------------------------------------------------------------------------
 
 QUANT_SHAPES = [
-    # b, t, h, kv, d, n_blocks, bs, maxb, window
+    # b, t, h, kv, d, n_blocks, bs, maxb, window[, layout]
     (2, 1, 8, 2, 64, 12, 16, 4, None),      # plain decode, GQA 4x
     (3, 6, 8, 8, 64, 20, 16, 5, None),      # verify, MHA
     (2, 11, 12, 4, 128, 9, 8, 6, None),     # verify, SL_max+1 queries
     (2, 4, 4, 2, 32, 10, 16, 4, 24),        # sliding window
+    (3, 5, 8, 2, 64, 40, 16, 13, None),     # MAXB not a multiple of P = 8
+    (3, 2, 8, 4, 64, 120, 16, 40, None, "spare"),   # extent << MAXB
+    (2, 3, 8, 2, 64, 30, 16, 10, None, "overflow"),  # q_pos past MAXB*BS
+    (2, 3, 4, 2, 32, 60, 16, 24, 40, "full"),  # window skips whole chunks
+    (2, 5, 9, 3, 64, 260, 16, 128, None, "full"),  # 16 chunks, smollm heads
 ]
 
 
-def _quant_attn_inputs(b, t, h, kv, d, n, bs, maxb, seed=0):
-    rng = np.random.RandomState(seed)
-    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    q = jax.random.normal(ks[0], (b, t, h, d))
-    pool_k = jax.random.normal(ks[1], (n, bs, kv, d))
-    pool_v = jax.random.normal(ks[2], (n, bs, kv, d))
+def _quant_attn_inputs(b, t, h, kv, d, n, bs, maxb, seed=0, layout="ragged"):
+    """``paged_attn_inputs`` (tables, positions and queries by ``layout``)
+    and the int8 pools of its fp pools."""
+    q, pool_k, pool_v, table, qpos, kvp = paged_attn_inputs(
+        b, t, h, kv, d, n, bs, maxb, seed=seed, layout=layout)
     qk, sk = cache_lib.quantize_kv(pool_k)
     qv, sv = cache_lib.quantize_kv(pool_v)
-    table = np.full((b, maxb), -1, np.int32)
-    kvp = np.full((n, bs), -1, np.int32)
-    qpos = np.zeros((b, t), np.int32)
-    perm = rng.permutation(n)
-    c = 0
-    for i in range(b):
-        avail = min(maxb, n - c - (b - 1 - i))
-        nb = rng.randint(1, max(avail, 1) + 1)
-        table[i, :nb] = perm[c:c + nb]
-        c += nb
-        ntok = rng.randint(t, max(nb * bs, t) + 1)
-        for p in range(min(ntok, nb * bs)):
-            kvp[table[i, p // bs], p % bs] = p
-        qpos[i] = np.arange(ntok - t, ntok)
-    return (q, pool_k, pool_v, qk, qv, sk, sv, jnp.asarray(table),
-            jnp.asarray(qpos), jnp.asarray(kvp))
+    return q, pool_k, pool_v, qk, qv, sk, sv, table, qpos, kvp
 
 
 @pytest.mark.parametrize("shape", QUANT_SHAPES)
 def test_quant_paged_kernel_vs_oracle(shape):
     """The JX006 parity contract for the quantized kernel: interpret-mode
     ``paged_ragged_verify_attention_quant`` against the pure-jnp oracle
-    over ragged scrambled tables, GQA, windows."""
-    b, t, h, kv, d, n, bs, maxb, window = shape
+    over ragged scrambled tables, GQA, windows, and live extents far
+    below, not a multiple of, or past the table's width."""
+    b, t, h, kv, d, n, bs, maxb, window, layout = (*shape, "ragged")[:10]
     (q, _, _, qk, qv, sk, sv, table, qpos,
-     kvp) = _quant_attn_inputs(b, t, h, kv, d, n, bs, maxb, seed=b * 10 + t)
+     kvp) = _quant_attn_inputs(b, t, h, kv, d, n, bs, maxb, seed=b * 10 + t,
+                               layout=layout)
     out = paged_ragged_verify_attention_quant(q, qk, qv, sk, sv, table,
                                               qpos, kvp, window=window,
                                               interpret=True)

@@ -12,13 +12,16 @@ from repro.configs import get_config
 from repro.core.config import ServingConfig, SpecDecodeConfig
 from repro.core.policies import available_policies
 from repro.kernels import ref
-from repro.kernels.ragged_attention import paged_ragged_verify_attention
+from repro.kernels.ragged_attention import (live_blocks,
+                                            paged_ragged_verify_attention)
 from repro.models import cache as cache_lib
 from repro.models.module import init_params
 from repro.models.transformer import model_specs
 from repro.serving.engine import ServingEngine
 from repro.serving.request import Request, RequestState
 from repro.serving.scheduler import BlockAllocator, LookaheadScheduler
+
+from paged_inputs import paged_attn_inputs
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -66,7 +69,8 @@ def _paged_sched(slots=2, max_seq=64, bs=8, nblocks=None):
 
 
 def test_paged_admission_charges_prefill_blocks():
-    s = _paged_sched(slots=2, max_seq=64, bs=8, nblocks=8)
+    # 7 blocks charged, 2 left as r1's growth headroom
+    s = _paged_sched(slots=2, max_seq=64, bs=8, nblocks=9)
     r1 = Request(0, prompt=[1] * 20, max_new_tokens=8)   # 3 blocks
     r2 = Request(1, prompt=[1] * 30, max_new_tokens=8)   # 4 blocks
     s.submit(r1), s.submit(r2)
@@ -88,12 +92,12 @@ def test_paged_admission_queues_when_pool_dry():
 
 
 def test_grow_preempts_youngest_and_readmits():
-    s = _paged_sched(slots=2, max_seq=64, bs=8, nblocks=8)
+    s = _paged_sched(slots=2, max_seq=64, bs=8, nblocks=9)
     old = Request(0, prompt=[1] * 24, max_new_tokens=20)  # 3 blocks
     young = Request(1, prompt=[1] * 24, max_new_tokens=20)
     s.submit(old), s.submit(young)
     assert len(s.admit()) == 2
-    assert s.allocator.n_free == 2
+    assert s.allocator.n_free == 3
     # old wants 5 more blocks: must evict young
     new, preempted = s.ensure_capacity(old, 64)
     assert preempted == [young]
@@ -108,6 +112,20 @@ def test_grow_preempts_youngest_and_readmits():
     assert young.prefill_tokens() == [1] * 24 + [5]
     assert s.admit() == [young]
     assert len(young.block_ids) == 4             # 25-token recompute prefix
+
+
+def test_paged_admission_leaves_growth_headroom():
+    """Admission keeps two free blocks for every running sequence, so the
+    next rounds' growth does not have to preempt what was just admitted."""
+    s = _paged_sched(slots=3, max_seq=64, bs=8, nblocks=12)
+    r1, r2, r3 = (Request(i, prompt=[1] * 24, max_new_tokens=8)  # 3 blocks
+                  for i in range(3))
+    for r in (r1, r2, r3):
+        s.submit(r)
+    assert s.admit() == [r1, r2]                 # r3 would leave 3 < 2 * 2
+    assert r3.state == RequestState.QUEUED and s.allocator.n_free == 6
+    s.release(r2)
+    assert s.admit() == [r3]                     # 9 - 3 >= 2 * 1
 
 
 def test_oversize_is_rejected_not_silently_dropped():
@@ -203,50 +221,49 @@ def test_paged_cache_struct_shapes_and_guard():
 # ---------------------------------------------------------------------------
 
 PAGED_SHAPES = [
-    # b, t, h, kv, d, n_blocks, bs, maxb, window
+    # b, t, h, kv, d, n_blocks, bs, maxb, window[, layout]
     (2, 1, 8, 2, 64, 12, 16, 4, None),      # plain decode, GQA 4x
     (3, 6, 8, 8, 64, 20, 16, 5, None),      # verify, MHA
     (2, 11, 12, 4, 128, 9, 8, 6, None),     # verify, SL_max+1 queries
     (2, 4, 4, 2, 32, 10, 16, 4, 24),        # sliding window
+    (3, 5, 8, 2, 64, 40, 16, 13, None),     # MAXB not a multiple of P = 8
+    (3, 2, 8, 4, 64, 120, 16, 40, None, "spare"),   # extent << MAXB
+    (2, 3, 8, 2, 64, 30, 16, 10, None, "overflow"),  # q_pos past MAXB*BS
+    (2, 3, 4, 2, 32, 60, 16, 24, 40, "full"),  # window skips whole chunks
+    (2, 5, 9, 3, 64, 260, 16, 128, None, "full"),  # 16 chunks, smollm heads
 ]
-
-
-def _paged_attn_inputs(b, t, h, kv, d, n, bs, maxb, seed=0):
-    rng = np.random.RandomState(seed)
-    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    q = jax.random.normal(ks[0], (b, t, h, d))
-    pool_k = jax.random.normal(ks[1], (n, bs, kv, d))
-    pool_v = jax.random.normal(ks[2], (n, bs, kv, d))
-    table = np.full((b, maxb), -1, np.int32)
-    kvp = np.full((n, bs), -1, np.int32)
-    qpos = np.zeros((b, t), np.int32)
-    perm = rng.permutation(n)
-    c = 0
-    for i in range(b):
-        # ragged table lengths, leaving >= 1 pool block per remaining row
-        avail = min(maxb, n - c - (b - 1 - i))
-        nb = rng.randint(1, max(avail, 1) + 1)
-        table[i, :nb] = perm[c:c + nb]
-        c += nb
-        # ragged sequence lengths; clamp so short tables stay valid (a
-        # query past the allocated blocks just attends a partial history)
-        ntok = rng.randint(t, max(nb * bs, t) + 1)
-        for p in range(min(ntok, nb * bs)):
-            kvp[table[i, p // bs], p % bs] = p
-        qpos[i] = np.arange(ntok - t, ntok)
-    return (q, pool_k, pool_v, jnp.asarray(table), jnp.asarray(qpos),
-            jnp.asarray(kvp))
 
 
 @pytest.mark.parametrize("shape", PAGED_SHAPES)
 def test_paged_kernel_vs_oracle(shape):
-    b, t, h, kv, d, n, bs, maxb, window = shape
-    q, pk, pv, table, qpos, kvp = _paged_attn_inputs(b, t, h, kv, d, n, bs,
-                                                     maxb, seed=b * 10 + t)
+    b, t, h, kv, d, n, bs, maxb, window, layout = (*shape, "ragged")[:10]
+    q, pk, pv, table, qpos, kvp = paged_attn_inputs(
+        b, t, h, kv, d, n, bs, maxb, seed=b * 10 + t, layout=layout)
     out = paged_ragged_verify_attention(q, pk, pv, table, qpos, kvp,
                                         window=window, interpret=True)
     want = ref.paged_ragged_verify_attention_ref(q, pk, pv, table, qpos,
                                                  kvp, window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-5, rtol=1e-4)
+
+
+def test_paged_kernel_never_reads_past_live_extent():
+    """Every pool block that a row's table holds only past the row's last
+    query position is NaN: the kernel neither copies nor computes on it,
+    so its output is finite and equals the oracle's on a finite pool."""
+    b, t, h, kv, d, n, bs, maxb = 3, 3, 8, 2, 64, 120, 16, 40
+    q, pk, pv, table, qpos, kvp = paged_attn_inputs(
+        b, t, h, kv, d, n, bs, maxb, seed=3, layout="spare")
+    tab = np.asarray(table)
+    hi = live_blocks(np.asarray(qpos).max(axis=1), bs, maxb)
+    live = {int(e) for i in range(b) for e in tab[i, :hi[i]] if e >= 0}
+    dead = sorted({int(e) for e in tab[tab >= 0]} - live)
+    assert len(dead) > b * 20
+    poisoned = [p.at[jnp.asarray(dead)].set(jnp.nan) for p in (pk, pv)]
+    out = paged_ragged_verify_attention(q, *poisoned, table, qpos, kvp,
+                                        interpret=True)
+    want = ref.paged_ragged_verify_attention_ref(q, pk, pv, table, qpos, kvp)
+    assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-5, rtol=1e-4)
 
@@ -368,6 +385,41 @@ def test_paged_round_log_telemetry(small_pair):
     assert m["kv_blocks_peak"] >= 1
 
 
+def test_round_log_counts_verify_blocks_swept(small_pair):
+    """The round log's ``verify_blocks_swept`` is the verify sweep's
+    extent as the round program sees it: ``live_blocks`` of each carried
+    row's last query position, the device's committed length + K."""
+    cfg, pt, pd = small_pair
+    rng = np.random.RandomState(3)
+    spec = SpecDecodeConfig(policy="dsde", temperature=0.0)
+    sv = ServingConfig(max_batch_size=3, max_seq_len=128, paged_kv=True,
+                       kv_block_size=4)
+    eng = ServingEngine(pt, cfg, pd, cfg, spec, sv, seed=0)
+    for i in range(5):
+        eng.submit(Request(i, prompt=rng.randint(
+            0, cfg.vocab_size, size=rng.randint(5, 25)).tolist(),
+            max_new_tokens=int(rng.randint(8, 24))))
+    seen = []
+    round_fn = eng._round_fn
+
+    def spy(k):
+        fn = round_fn(k)
+
+        def call(state, active):
+            length = np.asarray(state.target_cache["length"])
+            slots = [r.slot for r in eng.scheduler.running]
+            seen.append(int(live_blocks(length[slots] + k, 4,
+                                        sv.blocks_per_seq()).sum()))
+            return fn(state, active)
+        return call
+
+    eng._round_fn = spy
+    while eng.scheduler.has_work():
+        eng.step()
+    assert [r["verify_blocks_swept"] for r in eng.round_log] == seen
+    assert max(seen) > 3 and len(set(seen)) > 3
+
+
 def test_device_tables_mirror_allocator_every_round(small_pair):
     """Regression: post-round shrink must drop freed entries from the
     *device* block-table row immediately — a stale entry would gather a
@@ -406,6 +458,49 @@ def test_device_tables_mirror_allocator_every_round(small_pair):
             owned += req.block_ids
         assert len(owned) == len(set(owned))     # single ownership
     assert freed_events                           # the scenario occurred
+
+
+@pytest.mark.parametrize("caching", [False, True],
+                         ids=["preempt", "prefix_cache"])
+def test_pool_positions_stay_in_their_logical_block(small_pair, caching):
+    """The paged kernel skips a table column once its first position
+    lies past the row's last query: it relies on every slot of logical
+    block ``j`` holding a position in ``[j*BS, (j+1)*BS)`` or -1.  After
+    every pump of the pipelined engine, for every running row, through
+    growth, post-round shrink and regrow, preemption and readmission, and
+    with prefix-cache sharing and copy-on-write forks."""
+    cfg, pt, pd = small_pair
+    rng = np.random.RandomState(5)
+    bs = 8
+    shared = rng.randint(0, cfg.vocab_size, size=24).tolist()
+    prompts = [(shared if caching else [])
+               + rng.randint(0, cfg.vocab_size, size=n).tolist()
+               for n in ((6, 3, 1, 0) if caching else (30, 25, 20, 12))]
+    spec = SpecDecodeConfig(policy="dsde", temperature=0.0)
+    sv = ServingConfig(max_batch_size=2, max_seq_len=128, paged_kv=True,
+                       kv_block_size=bs, num_kv_blocks=16, pipelined=True,
+                       prefix_caching=caching)
+    eng = ServingEngine(pt, cfg, pd, cfg, spec, sv, seed=0)
+    for i, p in enumerate(prompts):
+        eng.submit(Request(i, prompt=p, max_new_tokens=40))
+    checked = 0
+    while eng.has_pending_work():
+        eng.pump()
+        tc = eng.state.target_cache
+        table = np.asarray(tc["block_table"])
+        kv_pos = np.asarray(tc["kv_pos"])
+        for req in eng.scheduler.running:
+            for j, blk in enumerate(table[req.slot]):
+                if blk < 0:
+                    continue
+                pos = kv_pos[blk]
+                ok = (pos == -1) | ((pos >= j * bs) & (pos < (j + 1) * bs))
+                assert ok.all(), (req.request_id, j, blk, pos)
+                checked += 1
+    eng.drain()
+    assert checked and eng.scheduler.preempted_total >= 1
+    if caching:
+        assert eng.scheduler.prefix_hit_blocks_total > 0
 
 
 def test_admission_refreshes_scheduler_sl_mirror(small_pair):

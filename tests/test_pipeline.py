@@ -227,14 +227,16 @@ def test_preempted_finished_at_first_token_never_readmitted(small_pair):
     would be readmitted as a permanently-dead device row, hanging
     ``run()``.  Pool sized so the older request's first growth (which
     carries the in-flight staleness slack) evicts the young 1-token
-    request exactly one plan after both were admitted together."""
+    request exactly one plan after both were admitted together: the
+    young one's admission leaves just the two blocks of headroom the
+    older one is owed."""
     cfg, pt, pd = small_pair
-    a = Request(0, prompt=list(range(1, 102)), max_new_tokens=12)  # 7 blocks
+    a = Request(0, prompt=list(range(1, 102)), max_new_tokens=12)  # 13 blocks
     b = Request(1, prompt=list(range(1, 9)), max_new_tokens=1)     # 1 block
     first_b = greedy_rollout(pt, cfg, b.prompt, 1)
     spec = SpecDecodeConfig(policy="dsde", temperature=0.0)
     sv = ServingConfig(max_batch_size=2, max_seq_len=128, paged_kv=True,
-                       kv_block_size=16, num_kv_blocks=8, pipelined=True)
+                       kv_block_size=8, num_kv_blocks=16, pipelined=True)
     eng = ServingEngine(pt, cfg, pd, cfg, spec, sv, seed=0)
     m = eng.run([a, b], max_rounds=40)      # bounded: a hang would loop
     assert b.preemptions >= 1               # the scenario actually occurred

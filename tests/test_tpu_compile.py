@@ -63,26 +63,33 @@ def _sds(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("k", [1, 10])
-def test_paged_verify_fp_compiles(one_chip, k):
+# (K, rows, pool blocks): batch 16 at K 1 and 10, and the offline
+# benchmark cell's verify call (batch 128, K 4, a 4096-block pool)
+PAGED_CALLS = dict(argnames="k, b, n",
+                   argvalues=[(1, B, N), (10, B, N), (4, 128, 4096)],
+                   ids=["1", "10", "offline"])
+
+
+@pytest.mark.parametrize(**PAGED_CALLS)
+def test_paged_verify_fp_compiles(one_chip, k, b, n):
     s = functools.partial(_sds, one_chip)
     txt = _compiled_text(
         paged_ragged_verify_attention,
-        s((B, k + 1, H, D), jnp.float32), s((N, BS, KV, D), jnp.float32),
-        s((N, BS, KV, D), jnp.float32), s((B, MAXB), jnp.int32),
-        s((B, k + 1), jnp.int32), s((N, BS), jnp.int32))
+        s((b, k + 1, H, D), jnp.float32), s((n, BS, KV, D), jnp.float32),
+        s((n, BS, KV, D), jnp.float32), s((b, MAXB), jnp.int32),
+        s((b, k + 1), jnp.int32), s((n, BS), jnp.int32))
     assert "tpu_custom_call" in txt
 
 
-@pytest.mark.parametrize("k", [1, 10])
-def test_paged_verify_int8_compiles(one_chip, k):
+@pytest.mark.parametrize(**PAGED_CALLS)
+def test_paged_verify_int8_compiles(one_chip, k, b, n):
     s = functools.partial(_sds, one_chip)
     txt = _compiled_text(
         paged_ragged_verify_attention_quant,
-        s((B, k + 1, H, D), jnp.float32), s((N, BS, KV, D), jnp.int8),
-        s((N, BS, KV, D), jnp.int8), s((N, BS, KV), jnp.float32),
-        s((N, BS, KV), jnp.float32), s((B, MAXB), jnp.int32),
-        s((B, k + 1), jnp.int32), s((N, BS), jnp.int32))
+        s((b, k + 1, H, D), jnp.float32), s((n, BS, KV, D), jnp.int8),
+        s((n, BS, KV, D), jnp.int8), s((n, BS, KV), jnp.float32),
+        s((n, BS, KV), jnp.float32), s((b, MAXB), jnp.int32),
+        s((b, k + 1), jnp.int32), s((n, BS), jnp.int32))
     assert "tpu_custom_call" in txt
 
 
