@@ -32,7 +32,8 @@ Every phase runs under a profiler span (``jax.profiler.TraceAnnotation``,
 which costs a flag check while no profile is being taken): each
 ``pump()`` is a step ``engine.round`` numbered by the round it
 dispatches, holding ``engine.plan`` (``engine.admit`` ⊃
-``engine.prefill`` per prefill group, ``engine.pick_bucket``,
+``engine.prefill`` per prefill group ⊃ ``engine.prefill_draft``, the
+drafter's side of it; ``engine.pick_bucket``,
 ``engine.plan_blocks`` ⊃ ``engine.block_sync``), ``engine.dispatch``
 (``engine.round_call``) and ``engine.collect`` (``engine.collect_wait``,
 ``engine.reconcile``, ``engine.block_sync``).  The round log books each
@@ -594,28 +595,31 @@ class ServingEngine:
         # the tokens directly — no draft prefill program at all
         rows_mask = jnp.zeros((self.serving.max_batch_size,),
                               bool).at[idx].set(True)
-        dc = self.drafter.reset_rows(st.draft_cache, rows_mask)
-        mirror_rows = (rows_j if (self.paged and self.drafter.mirrors_kv())
-                       else None)
-        if warm:
-            # token-history drafters need the FULL prefix whatever the
-            # KV coverage; mirroring drafters run the tail program over
-            # their own pools and ignore it
-            fbucket = _bucket(int(plens.max()), cap=self.serving.max_seq_len)
-            full_np = np.zeros((r, fbucket), np.int32)
-            for i, prefix in enumerate(prefixes):
-                full_np[i, :len(prefix)] = prefix
-            dc = self.drafter.prefill_tail(
-                self.pd, dc, idx, jnp.asarray(full_np), plen_j,
-                toks, starts_j, tails_j, cow_src_j, cow_dst_j,
-                max_len=self.serving.max_seq_len,
-                table_rows=mirror_rows, plan=self._plan)
-        else:
-            dc = self.drafter.prefill(
-                self.pd, dc, idx, toks, plen_j,
-                max_len=self.serving.max_seq_len,
-                table_rows=mirror_rows,
-                plan=self._plan)
+        with _span("engine.prefill_draft"):
+            dc = self.drafter.reset_rows(st.draft_cache, rows_mask)
+            mirror_rows = (rows_j if (self.paged
+                                      and self.drafter.mirrors_kv())
+                           else None)
+            if warm:
+                # token-history drafters need the FULL prefix whatever
+                # the KV coverage; mirroring drafters run the tail
+                # program over their own pools and ignore it
+                fbucket = _bucket(int(plens.max()),
+                                  cap=self.serving.max_seq_len)
+                full_np = np.zeros((r, fbucket), np.int32)
+                for i, prefix in enumerate(prefixes):
+                    full_np[i, :len(prefix)] = prefix
+                dc = self.drafter.prefill_tail(
+                    self.pd, dc, idx, jnp.asarray(full_np), plen_j,
+                    toks, starts_j, tails_j, cow_src_j, cow_dst_j,
+                    max_len=self.serving.max_seq_len,
+                    table_rows=mirror_rows, plan=self._plan)
+            else:
+                dc = self.drafter.prefill(
+                    self.pd, dc, idx, toks, plen_j,
+                    max_len=self.serving.max_seq_len,
+                    table_rows=mirror_rows,
+                    plan=self._plan)
         # pending token per row: sampled at prefill for fresh requests
         # (per-request keys — schedule/grouping invariant), the
         # already-emitted last token for readmits
@@ -948,6 +952,8 @@ class ServingEngine:
         if rec.k > 0 and live.any():
             eff_steps = int(n_prop[live].max()) + 1
             self.draft_steps_effective += eff_steps
+        # the round's effective draft depth, of the k + 1 steps dispatched
+        round_rec["draft_steps_effective"] = float(eff_steps)
         # what this round's drafting actually cost, in target-
         # verification units — the capacity-vs-latency number that makes
         # model-free drafters' wins visible in benchmark rows

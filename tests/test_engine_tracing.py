@@ -25,6 +25,7 @@ PARENTS = {
     "engine.plan": {"engine.round"},
     "engine.admit": {"engine.plan"},
     "engine.prefill": {"engine.admit"},
+    "engine.prefill_draft": {"engine.prefill"},
     "engine.pick_bucket": {"engine.plan"},
     "engine.plan_blocks": {"engine.plan"},
     "engine.block_sync": {"engine.plan_blocks", "engine.collect",
@@ -155,3 +156,49 @@ def test_phase_cpu_counters_within_the_pump_clock(pipelined):
                  for r in eng.round_log)
     assert 0.0 < booked <= total
     assert [r["round"] for r in eng.round_log] == list(range(eng.rounds))
+
+
+def test_model_drafter_rounds_book_their_draft_depth(tmp_path):
+    """A model drafter sampled at T = 1 in the pipelined engine: every
+    round is dispatched at ``sl_max``, and the round log books the depth
+    the round's rows used (their most proposals, plus the step that
+    writes the last draft token's keys), within the ``k + 1`` steps
+    dispatched.  An admission runs the draft's prefill under its own
+    span inside the group's ``engine.prefill``."""
+    over = tiny.overrides("granite8b-l9.chat")
+    cfg = harness._merge(harness.load_config("granite-8b-l9"),
+                         over["config"])
+    eng = harness.build(cfg, 5).engine
+    assert eng.spec.temperature == 1.0 and eng.serving.pipelined
+    depth = []
+    log_round = eng._log_round
+
+    def spy(rec, host, *a):
+        depth.append(int(host.n_prop[host.live].max()) + 1
+                     if rec.k > 0 and host.live.any() else 0)
+        return log_round(rec, host, *a)
+    eng._log_round = spy
+    _submit(eng, 3, np.random.default_rng(2), max_new=16)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for _ in range(2):
+            eng.pump()
+    finally:
+        jax.profiler.stop_trace()
+    harness.run_engine_until_idle(eng)
+    assert len(eng.round_log) >= 3
+    for r, want in zip(eng.round_log, depth, strict=True):
+        assert r["k"] == eng.spec.sl_max
+        assert r["draft_steps_effective"] == want
+        # 0 for a round whose rows had all finished before it ran
+        assert 0 <= r["draft_steps_effective"] <= r["k"] + 1
+    assert min(depth[:2]) >= 1
+    assert eng.draft_steps_effective == sum(depth)
+    spans = [e for e in traces.load(str(tmp_path))
+             if e["name"].startswith("engine.") and e["dur_ns"] > 0]
+    (pre,) = [e for e in spans if e["name"] == "engine.prefill"]
+    (draft,) = [e for e in spans if e["name"] == "engine.prefill_draft"]
+    assert _innermost_parent(draft, spans) is pre
